@@ -1,4 +1,5 @@
-"""qwen2.5-3b [dense]: GQA kv=2, QKV bias. [hf:Qwen/Qwen2.5 family]"""
+"""qwen2.5-3b [dense]: GQA kv=2, QKV bias, tied embeddings.
+[hf:Qwen/Qwen2.5-3B config.json: tie_word_embeddings=true, rms_norm_eps=1e-6]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -6,4 +7,5 @@ CONFIG = ModelConfig(
     n_layers=36, d_model=2048, n_heads=16, n_kv_heads=2,
     d_ff=11008, vocab_size=151936,
     qkv_bias=True, activation="silu", rope_theta=1e6,
+    norm_eps=1e-6, tie_embeddings=True,
 )

@@ -6,8 +6,8 @@ bytes-touched cost O(C) per page. The direct kernel touches one layer:
 O(1). The ``*_fleet_pallas`` entry points extend both to the stacked
 (T, C, P) multi-tenant layout of ``core.fleet``: the grid runs over the
 tenant axis, per-tenant chain ``length`` is prefetched as a scalar (the
-direct kernel's BlockSpec index_map uses it to stage *only* each tenant's
-active layer), and the fleet kernels consume the packed L2 words of
+direct kernel's BlockSpec index_map stages *only* the sublane group
+holding each tenant's active layer), and the fleet kernels consume the packed L2 words of
 ``core.format`` directly — the kernel reads the actual table format, as
 the paper's sQemu data plane does.
 
@@ -26,30 +26,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import format as fmt
+from repro.kernels.common import SUBLANES, first_hit_walk
 
 PAGE_TILE = 512  # lanes per grid step (4 × 128)
 
 
 def _vanilla_kernel(length_ref, alloc_ref, ptr_ref, owner_ref, out_ptr_ref):
-    c = alloc_ref.shape[0]
-    length = length_ref[0]
+    def row_at(idx):
+        return (alloc_ref[pl.ds(idx, 1), :] != 0, ptr_ref[pl.ds(idx, 1), :])
 
-    owner = jnp.full((1, alloc_ref.shape[1]), -1, jnp.int32)
-    ptr = jnp.zeros((1, alloc_ref.shape[1]), jnp.uint32)
-
-    def body(i, carry):
-        owner, ptr = carry
-        # walk from the active volume (length-1) downwards
-        layer = length - 1 - i
-        valid = (layer >= 0) & (layer < c)
-        idx = jnp.maximum(layer, 0)
-        a = (alloc_ref[idx, :] != 0) & valid
-        hit = a & (owner[0] < 0)
-        owner = owner.at[0].set(jnp.where(hit, layer, owner[0]))
-        ptr = ptr.at[0].set(jnp.where(hit, ptr_ref[idx, :], ptr[0]))
-        return owner, ptr
-
-    owner, ptr = jax.lax.fori_loop(0, c, body, (owner, ptr))
+    owner, ptr = first_hit_walk(length_ref[0], alloc_ref.shape[0], row_at,
+                                jnp.zeros(out_ptr_ref.shape, jnp.uint32))
     owner_ref[...] = owner
     out_ptr_ref[...] = ptr
 
@@ -116,29 +103,27 @@ def resolve_direct_pallas(alloc_active, bfi_active, ptrs_active, *,
 
 
 def _vanilla_fleet_kernel(length_ref, w0_ref, owner_ref, hit_ref):
-    c = w0_ref.shape[1]
-    width = w0_ref.shape[2]
-    length = length_ref[pl.program_id(0)]
+    def row_at(idx):
+        w = w0_ref[pl.ds(idx, 1), :]
+        return (w & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0, w
 
-    owner = jnp.full((1, width), -1, jnp.int32)
-    hit = jnp.zeros((1, width), jnp.uint32)
-
-    def body(i, carry):
-        owner, hit = carry
-        # walk from the tenant's active volume (length-1) downwards
-        layer = length - 1 - i
-        valid = (layer >= 0) & (layer < c)
-        idx = jnp.maximum(layer, 0)
-        w = w0_ref[0, idx, :]
-        a = (w & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
-        first = a & valid & (owner[0] < 0)
-        owner = owner.at[0].set(jnp.where(first, layer, owner[0]))
-        hit = hit.at[0].set(jnp.where(first, w, hit[0]))
-        return owner, hit
-
-    owner, hit = jax.lax.fori_loop(0, c, body, (owner, hit))
+    owner, hit = first_hit_walk(length_ref[pl.program_id(0)],
+                                w0_ref.shape[0], row_at,
+                                jnp.zeros(hit_ref.shape, jnp.uint32))
     owner_ref[...] = owner
     hit_ref[...] = hit
+
+
+def _row_out(tile):
+    """Out block of the fleet kernels: one tenant's row of a (T, 1, P)
+    array. The squeezed tenant dim leaves a (1, tile) block over a (1, P)
+    extent — a whole second-minor dim, which the TPU tiling accepts where
+    a (1, tile) block over (T, P) is refused."""
+    return pl.BlockSpec((None, 1, tile), lambda ti, pi, *prefetch: (ti, 0, pi))
+
+
+def _row_shapes(t, p, *dtypes):
+    return [jax.ShapeDtypeStruct((t, 1, p), d) for d in dtypes]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -160,38 +145,36 @@ def resolve_vanilla_fleet_pallas(w0, lengths, *, interpret: bool = True):
             num_scalar_prefetch=1,
             grid=(t, pl.cdiv(p, tile)),
             in_specs=[
-                pl.BlockSpec((1, c, tile), lambda ti, pi, ln: (ti, 0, pi)),
+                pl.BlockSpec((None, c, tile), lambda ti, pi, ln: (ti, 0, pi)),
             ],
-            out_specs=[
-                pl.BlockSpec((1, tile), lambda ti, pi, ln: (ti, pi)),
-                pl.BlockSpec((1, tile), lambda ti, pi, ln: (ti, pi)),
-            ],
+            out_specs=[_row_out(tile), _row_out(tile)],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((t, p), jnp.int32),
-            jax.ShapeDtypeStruct((t, p), jnp.uint32),
-        ],
+        out_shape=_row_shapes(t, p, jnp.int32, jnp.uint32),
         interpret=interpret,
     )(lengths.astype(jnp.int32), w0.astype(jnp.uint32))
-    return owner, hit
+    return owner[:, 0], hit[:, 0]
 
 
-def _direct_fleet_kernel(length_ref, w0_ref, w1_ref, owner_ref, h0_ref, h1_ref):
-    w0 = w0_ref[0, 0, :]
-    w1 = w1_ref[0, 0, :]
+def _direct_fleet_kernel(length_ref, group_ref, w0_ref, w1_ref, owner_ref,
+                         h0_ref, h1_ref):
+    # the block is the sublane group holding the active layer; pick its row
+    r = (length_ref[pl.program_id(0)] - 1) % w0_ref.shape[0]
+    w0 = w0_ref[pl.ds(r, 1), :]
+    w1 = w1_ref[pl.ds(r, 1), :]
     alloc = (w0 & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
     bfi = (w1 & jnp.uint32(fmt.BFI_MASK)).astype(jnp.int32)
-    owner_ref[...] = jnp.where(alloc, bfi, -1)[None]
-    h0_ref[...] = w0[None]
-    h1_ref[...] = w1[None]
+    owner_ref[...] = jnp.where(alloc, bfi, -1)
+    h0_ref[...] = w0
+    h1_ref[...] = w1
 
 
 @partial(jax.jit, static_argnames=("interpret",))
 def resolve_direct_fleet_pallas(w0, w1, lengths, *, interpret: bool = True):
     """Stacked sQEMU direct access: one layer per tenant, picked by the
-    BlockSpec index_map from the prefetched ``lengths`` — only each
-    tenant's active layer is ever staged into VMEM, so the bytes-touched
-    cost is O(1) per page regardless of chain length.
+    BlockSpec index_map from the prefetched ``lengths`` — only the
+    sublane group (``SUBLANES`` layers) holding each tenant's active layer
+    is ever staged into VMEM, so the bytes-touched cost is O(1) per page
+    regardless of chain length.
 
     ``w0``/``w1``: (T, C, P) uint32 packed L2 words; ``lengths``: (T,).
 
@@ -200,21 +183,20 @@ def resolve_direct_fleet_pallas(w0, w1, lengths, *, interpret: bool = True):
     """
     t, c, p = w0.shape
     tile = min(PAGE_TILE, p)
-    in_spec = pl.BlockSpec((1, 1, tile), lambda ti, pi, ln: (ti, ln[ti] - 1, pi))
-    out_spec = pl.BlockSpec((1, tile), lambda ti, pi, ln: (ti, pi))
+    sub = min(SUBLANES, c)
+    lengths = lengths.astype(jnp.int32)
+    in_spec = pl.BlockSpec((None, sub, tile),
+                           lambda ti, pi, ln, grp: (ti, grp[ti], pi))
     owner, h0, h1 = pl.pallas_call(
         _direct_fleet_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(t, pl.cdiv(p, tile)),
             in_specs=[in_spec, in_spec],
-            out_specs=[out_spec, out_spec, out_spec],
+            out_specs=[_row_out(tile)] * 3,
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct((t, p), jnp.int32),
-            jax.ShapeDtypeStruct((t, p), jnp.uint32),
-            jax.ShapeDtypeStruct((t, p), jnp.uint32),
-        ],
+        out_shape=_row_shapes(t, p, jnp.int32, jnp.uint32, jnp.uint32),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), w0.astype(jnp.uint32), w1.astype(jnp.uint32))
-    return owner, h0, h1
+    )(lengths, (lengths - 1) // sub, w0.astype(jnp.uint32),
+      w1.astype(jnp.uint32))
+    return owner[:, 0], h0[:, 0], h1[:, 0]

@@ -18,9 +18,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_kernel(rows_ref, found_ref, pool_ref, out_ref):
-    i = pl.program_id(0)
-    ok = found_ref[i] != 0
-    out_ref[...] = jnp.where(ok, pool_ref[...], jnp.zeros_like(pool_ref[...]))
+    ok = found_ref[pl.program_id(0)] != 0
+    out_ref[...] = jnp.where(ok, pool_ref[...], jnp.zeros_like(pool_ref))
+
+
+def _row_block(p, index_map):
+    """A (1, p) block of a (..., 1, P) view: the squeezed leading dims
+    leave a whole second-minor dim, which the TPU tiling accepts where a
+    (1, p) block over (R, P) is refused."""
+    return pl.BlockSpec((None, 1, p), index_map)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -32,27 +38,22 @@ def gather_pallas(pool, rows, found, *, interpret: bool = True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, p), lambda i, rows_ref, found_ref: (rows_ref[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, p), lambda i, rows_ref, found_ref: (i, 0)),
+        in_specs=[_row_block(p, lambda i, rows_ref, found_ref:
+                             (rows_ref[i], 0, 0))],
+        out_specs=_row_block(p, lambda i, rows_ref, found_ref: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, p), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, p), pool.dtype),
         interpret=interpret,
-    )(safe_rows, found.astype(jnp.int32), pool)
-    return out
+    )(safe_rows, found.astype(jnp.int32), pool.reshape(r, 1, p))
+    return out.reshape(b, p)
 
 
 def _gather_fleet_kernel(rows_ref, found_ref, pool_ref, out_ref):
-    t = pl.program_id(0)
-    i = pl.program_id(1)
-    ok = found_ref[t, i] != 0
-    out_ref[...] = jnp.where(
-        ok, pool_ref[...], jnp.zeros_like(pool_ref[...])
-    )[None]
+    ok = found_ref[pl.program_id(0), pl.program_id(1)] != 0
+    out_ref[...] = jnp.where(ok, pool_ref[...], jnp.zeros_like(pool_ref))
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -70,17 +71,16 @@ def gather_fleet_pallas(pool, rows, found, *, interpret: bool = True):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(t, b),
-        in_specs=[
-            pl.BlockSpec((1, p), lambda ti, bi, rows_ref, found_ref:
-                         (rows_ref[ti, bi], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, p), lambda ti, bi, rows_ref, found_ref:
-                               (ti, bi, 0)),
+        in_specs=[_row_block(p, lambda ti, bi, rows_ref, found_ref:
+                             (rows_ref[ti, bi], 0, 0))],
+        out_specs=pl.BlockSpec((None, None, 1, p),
+                               lambda ti, bi, rows_ref, found_ref:
+                               (ti, bi, 0, 0)),
     )
     out = pl.pallas_call(
         _gather_fleet_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, b, p), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, b, 1, p), pool.dtype),
         interpret=interpret,
-    )(safe_rows, found.astype(jnp.int32), pool)
-    return out
+    )(safe_rows, found.astype(jnp.int32), pool.reshape(r, 1, p))
+    return out.reshape(t, b, p)

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import format as fmt
+from repro.kernels.common import first_hit_walk
 
 
 def _paged_attn_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, out_ref,
@@ -130,8 +131,7 @@ def _fused_chain_attn_kernel(tenants_ref, chain_len_ref, kvlen_ref,
     b = pl.program_id(0)
     j = pl.program_id(1)
     n_blocks = pl.num_programs(1)
-    c = w0_ref.shape[1]
-    p = w0_ref.shape[2]
+    c, p = w0_ref.shape
     bs, hkv, d = k_buf.shape
     h = q_ref.shape[1]
     g = h // hkv
@@ -145,26 +145,13 @@ def _fused_chain_attn_kernel(tenants_ref, chain_len_ref, kvlen_ref,
         # batch row's tenant stack resolves every page lane at once and
         # parks the pool rows in VMEM scratch for the whole kv sweep.
         # C == 1 (scalable tenants) makes this the O(1) direct lookup.
-        length = chain_len_ref[tenants_ref[b]]
-        owner = jnp.full((1, p), -1, jnp.int32)
-        rows = jnp.zeros((1, p), jnp.int32)
+        def row_at(idx):
+            w = w0_ref[pl.ds(idx, 1), :]
+            return ((w & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0,
+                    (w & jnp.uint32(fmt.PTR_MASK)).astype(jnp.int32))
 
-        def body(i, carry):
-            owner, rows = carry
-            # walk from the tenant's active volume (length-1) downwards
-            layer = length - 1 - i
-            valid = (layer >= 0) & (layer < c)
-            idx = jnp.maximum(layer, 0)
-            w = w0_ref[0, idx, :]
-            a = (w & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
-            first = a & valid & (owner[0] < 0)
-            owner = owner.at[0].set(jnp.where(first, layer, owner[0]))
-            rows = rows.at[0].set(jnp.where(
-                first, (w & jnp.uint32(fmt.PTR_MASK)).astype(jnp.int32),
-                rows[0]))
-            return owner, rows
-
-        owner, rows = jax.lax.fori_loop(0, c, body, (owner, rows))
+        owner, rows = first_hit_walk(chain_len_ref[tenants_ref[b]], c,
+                                     row_at, jnp.zeros((1, p), jnp.int32))
         rows_ref[...] = jnp.where(owner >= 0, rows, -1)
 
     # this block's resolved pool row: a masked reduce over the parked walk
@@ -242,9 +229,9 @@ def fused_chain_attention_pallas(q, pool_k, pool_v, w0, chain_lengths,
         grid=(b, p),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda b, j, tn, cl, kl: (b, 0, 0)),
-            pl.BlockSpec((1, c, p), lambda b, j, tn, cl, kl: (tn[b], 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((None, c, p), lambda b, j, tn, cl, kl: (tn[b], 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda b, j, tn, cl, kl: (b, 0, 0)),
         scratch_shapes=[

@@ -15,23 +15,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import first_hit_walk
+
 PAGE_TILE = 512
 
 
 def _merge_kernel(alloc_ref, ptr_ref, found_ref, out_ptr_ref, src_ref):
+    # last write wins bottom-up == the first hit walking down from the top
+    def row_at(idx):
+        return alloc_ref[pl.ds(idx, 1), :] != 0, ptr_ref[pl.ds(idx, 1), :]
+
     k = alloc_ref.shape[0]
-    n = alloc_ref.shape[1]
-    src = jnp.full((1, n), -1, jnp.int32)
-    ptr = jnp.zeros((1, n), jnp.uint32)
-
-    def body(i, carry):
-        src, ptr = carry
-        a = alloc_ref[i, :] != 0
-        src = src.at[0].set(jnp.where(a, i, src[0]))     # last write wins
-        ptr = ptr.at[0].set(jnp.where(a, ptr_ref[i, :], ptr[0]))
-        return src, ptr
-
-    src, ptr = jax.lax.fori_loop(0, k, body, (src, ptr))
+    src, ptr = first_hit_walk(k, k, row_at,
+                              jnp.zeros(out_ptr_ref.shape, jnp.uint32))
     found_ref[...] = (src >= 0).astype(jnp.uint32)
     out_ptr_ref[...] = ptr
     src_ref[...] = src

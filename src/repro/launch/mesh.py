@@ -7,26 +7,8 @@ import jax
 
 
 def _mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and the
-    ``AxisType`` enum itself) only exist on newer releases."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
-
-
-def make_abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh`` across JAX versions: 0.4.x takes one
-    ``((name, size), ...)`` tuple; newer releases take (shape, names)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

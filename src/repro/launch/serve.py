@@ -8,13 +8,14 @@ from __future__ import annotations
 
 import argparse
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
-from repro.models import get_model
-from repro.serve.engine import Engine
+from repro.launch import use_compile_cache
+from repro.serve.engine import Engine, init_serving_params
 
 
 def main():
@@ -29,9 +30,9 @@ def main():
                     help="vanilla fork chains (walks) instead of direct")
     args = ap.parse_args()
 
+    use_compile_cache(Path(__file__).resolve().parents[3] / ".jax_cache")
     cfg = get_config(args.arch) if args.scale == "full" else smoke_config(args.arch)
-    model = get_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_serving_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, scalable=not args.vanilla, n_blocks=1024,
                  block_size=8, max_blocks_per_seq=64)
 

@@ -70,6 +70,26 @@ from repro.serve.paged_decode import (
 )
 
 
+def serving_params(params):
+    """Serving weights: every floating leaf in ``COMPUTE_DTYPE`` (bf16).
+
+    Every layer casts its weights to the compute dtype at use, so storing
+    them in bf16 changes no result; it halves their bytes, and inside the
+    decode step's layer scan there is no f32 stack for XLA to cast (and
+    keep) whole."""
+    return jax.tree.map(
+        lambda a: (a.astype(L.COMPUTE_DTYPE)
+                   if jnp.issubdtype(a.dtype, jnp.floating) else a),
+        params)
+
+
+def init_serving_params(cfg: ModelConfig, key):
+    """Random serving weights from ``key``, built in bf16 inside one jit,
+    so the float32 initialisation never lands whole on the device."""
+    model = get_model(cfg)
+    return jax.jit(lambda k: serving_params(model.init(k)))(key)
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, *, scalable: bool = True,
                  n_blocks: int = 512, block_size: int = 16,
@@ -91,7 +111,7 @@ class Engine:
             )
         self.decode_path = decode_path
         self.cfg = cfg
-        self.params = params
+        self.params = serving_params(params)
         self.model = get_model(cfg)
         self.kv = PagedKVCache(
             PagedKVConfig(
@@ -122,6 +142,9 @@ class Engine:
         # decode steps — the background half of the serving loop.
         self.scheduler = scheduler
         self.last_maintenance: dict | None = None
+        # (padded batch, vocab) f32 logits of the last decode dispatch,
+        # left on the device: what a caller compares with a reference
+        self.last_logits: jax.Array | None = None
 
     def _prefill_seq(self, prompt_tokens) -> tuple[int, int]:
         """Full-prompt prefill into a fresh sequence: one model prefill,
@@ -341,6 +364,7 @@ class Engine:
                 tables, lengths, jnp.asarray(tok_col),
             )
         self.kv.commit_pools(pk, pv)
+        self.last_logits = logits
         out = {}
         # the sampling boundary: greedy argmax must reach the host to
         # extend python-side sequences — the one designed sync in step()

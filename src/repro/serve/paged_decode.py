@@ -25,7 +25,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels.paged_attention import ops as pa_ops
-from repro.kernels.paged_attention import ref as pa_ref
 from repro.models import layers as L
 from repro.models import moe as moe_lib
 from repro.models.transformer import output_matrix
@@ -143,11 +142,10 @@ def paged_decode_step_fused(cfg: ModelConfig, params, pool_k, pool_v, l2,
     ``PagedKVCache.prepare_step_fused`` stamped into the index before
     this jit, so the walk resolves the write block too.
 
-    Backend split (hot-path policy, ``docs/kernels.md``): on TPU every
-    layer runs the compiled fused kernel; elsewhere the batch's tables
-    are resolved ONCE inside this jit by the pinned walk oracle and the
-    table-consuming oracle serves every layer — still zero host-side
-    materialization, transfer or sync.
+    Backend split (hot-path policy, ``docs/kernels.md``,
+    ``pa_ops.fused_attention``): on TPU every layer runs the compiled
+    fused kernel; elsewhere the composed jnp oracle — still zero
+    host-side materialization, transfer or sync.
 
     pool_k/pool_v: (L, nb, bs, Hkv, D); l2: (T, C, P, 2) uint32;
     chain_lengths: (T,); tenants/lengths/write_blocks: (B,) int32;
@@ -159,9 +157,6 @@ def paged_decode_step_fused(cfg: ModelConfig, params, pool_k, pool_v, l2,
     positions = lengths[:, None]                             # (B,1)
     w0 = l2[..., 0]
     off = lengths % bs
-    on_tpu = jax.default_backend() == "tpu"
-    if not on_tpu:
-        tables = pa_ref.fused_tables_ref(w0, chain_lengths, tenants)
 
     def body(x, inputs):
         p, pk, pv = inputs
@@ -171,13 +166,9 @@ def paged_decode_step_fused(cfg: ModelConfig, params, pool_k, pool_v, l2,
                              use_rope=cfg.use_rope)
         pk = pk.at[write_blocks, off].set(k[:, 0].astype(pk.dtype))
         pv = pv.at[write_blocks, off].set(v[:, 0].astype(pv.dtype))
-        qh = q[:, 0].astype(L.COMPUTE_DTYPE)
-        if on_tpu:
-            attn = pa_ops.fused_attention(qh, pk, pv, w0, chain_lengths,
-                                          tenants, lengths + 1)
-        else:
-            attn = pa_ref.paged_attention_ref(qh, pk, pv, tables,
-                                              lengths + 1)
+        attn = pa_ops.fused_attention(q[:, 0].astype(L.COMPUTE_DTYPE), pk,
+                                      pv, w0, chain_lengths, tenants,
+                                      lengths + 1)
         x = x + attn.reshape(b, 1, -1).astype(x.dtype) @ p["attn"]["wo"].astype(x.dtype)
         h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
         if cfg.is_moe:

@@ -8,14 +8,13 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as sh
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_abstract_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # AbstractMesh: rule resolution needs only axis names/sizes, so tests
     # exercise the production 16x16 geometry without 256 devices.
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_rules_divisibility_guard(mesh):

@@ -1,0 +1,177 @@
+"""Compile every Pallas kernel and the serving decode step for a described
+TPU v5e, with no chip attached.
+
+The TPU compiler is installed beside the CPU backend, so a program can be
+lowered and compiled for a ``v5e:2x2`` topology described in software.
+That catches what interpret mode cannot: block shapes the tiling refuses,
+ops Mosaic does not lower (scatter), scoped-VMEM overruns, and a step
+program that does not fit the chip's memory. Nothing runs, so these tests
+say nothing about results or speed.
+
+Only one process at a time may load the TPU library, so the topology is
+described inside a module-scoped fixture (never at import), and every
+test that needs it lives in this one file.
+"""
+
+from __future__ import annotations
+
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.serve import engine as engine_lib  # first: imports repro.core
+from repro.serve.paged_decode import paged_decode_step, paged_decode_step_fused
+from repro.kernels.chain_resolve.chain_resolve import (
+    resolve_direct_fleet_pallas, resolve_direct_pallas,
+    resolve_vanilla_fleet_pallas, resolve_vanilla_pallas)
+from repro.kernels.cow_gather.cow_gather import gather_fleet_pallas, gather_pallas
+from repro.kernels.paged_attention import ops as pa_ops
+from repro.kernels.paged_attention.paged_attention import (
+    fused_chain_attention_pallas, paged_attention_pallas)
+from repro.kernels.stream_merge.stream_merge import merge_pallas
+
+#: Usable HBM of one v5e chip as XLA reports it ("of 15.75G hbm").
+V5E_HBM_BYTES = int(15.75 * 2**30)
+
+# The serving plane's widths (Qwen2.5-3B: 2 KV heads of 128, 16 heads)
+# and the fleet plane's: 64 tenants, 2048 lane-aligned pages, chains of
+# 1 (scalable) or 500 (the paper's deep vanilla regime).
+T, P, HKV, H, D, BS, NB = 64, 2048, 2, 16, 128, 16, 2048
+U32, I32 = jnp.uint32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e:2x2, with the persistent compilation
+    cache off: a TPU executable written here could not be read back on a
+    host without the chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(one_chip, fn, *shapes):
+    """Compile ``fn`` for the described chip from (shape, dtype) pairs."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("c", [1, 500])
+def test_fleet_resolve_kernels_compile(one_chip, c):
+    w0 = ((T, c, P), U32)
+    van = _compile(one_chip, lambda w, ln: resolve_vanilla_fleet_pallas(
+        w, ln, interpret=False), w0, ((T,), I32))
+    direct = _compile(one_chip, lambda a, b, ln: resolve_direct_fleet_pallas(
+        a, b, ln, interpret=False), w0, w0, ((T,), I32))
+    assert _has_kernel(van) and _has_kernel(direct)
+
+
+def test_fleet_gather_kernel_compiles(one_chip):
+    compiled = _compile(
+        one_chip, lambda pool, r, f: gather_fleet_pallas(
+            pool, r, f, interpret=False),
+        ((T * 4096, 128), jnp.float32), ((T, 512), I32), ((T, 512), jnp.bool_))
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("c", [1, 500])
+def test_fused_chain_attention_compiles(one_chip, c):
+    compiled = _compile(
+        one_chip, lambda *a: fused_chain_attention_pallas(*a, interpret=False),
+        ((16, H, D), jnp.bfloat16), ((NB, BS, HKV, D), jnp.bfloat16),
+        ((NB, BS, HKV, D), jnp.bfloat16), ((T, c, P), U32), ((T,), I32),
+        ((16,), I32), ((16,), I32))
+    assert _has_kernel(compiled)
+
+
+def test_paged_attention_compiles(one_chip):
+    compiled = _compile(
+        one_chip, lambda *a: paged_attention_pallas(*a, interpret=False),
+        ((16, H, D), jnp.bfloat16), ((NB, BS, HKV, D), jnp.bfloat16),
+        ((NB, BS, HKV, D), jnp.bfloat16), ((16, P), I32), ((16,), I32))
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("kernel", ["resolve_vanilla", "resolve_direct",
+                                    "gather", "merge"])
+def test_single_chain_kernels_compile(one_chip, kernel):
+    c, n = 500, P
+    fn, shapes = {
+        "resolve_vanilla": (
+            lambda a, p, ln: resolve_vanilla_pallas(a, p, ln, interpret=False),
+            [((c, n), U32), ((c, n), U32), ((), I32)]),
+        "resolve_direct": (
+            lambda a, b, p: resolve_direct_pallas(a, b, p, interpret=False),
+            [((n,), U32)] * 3),
+        "gather": (
+            lambda pool, r, f: gather_pallas(pool, r, f, interpret=False),
+            [((4096, 128), jnp.float32), ((512,), I32), ((512,), jnp.bool_)]),
+        "merge": (
+            lambda a, p: merge_pallas(a, p, interpret=False),
+            [((30, n), U32), ((30, n), U32)]),
+    }[kernel]
+    assert _has_kernel(_compile(one_chip, fn, *shapes))
+
+
+@pytest.fixture
+def tpu_attention(monkeypatch):
+    """Steer the attention dispatch to its TPU branch: under
+    ``JAX_PLATFORMS=cpu`` the backend reports CPU, and the decode step
+    would trace the jnp oracle instead of the kernel."""
+    monkeypatch.setattr(pa_ops, "jax",
+                        types.SimpleNamespace(default_backend=lambda: "tpu"))
+
+
+@pytest.mark.parametrize("path", ["tables", "fused"])
+def test_qwen2_5_3b_decode_step_fits_one_v5e(one_chip, tpu_attention, path):
+    """The serving decode step of Qwen2.5-3B at full width, bf16 weights,
+    a 2048-block pool of 16-token blocks and 128 blocks per sequence,
+    compiles for one v5e with the kernel in the program, and its
+    arguments, outputs and temporaries fit the chip's memory."""
+    cfg = get_config("qwen2.5-3b")
+    b, mb = 16, 128
+    params = jax.eval_shape(
+        lambda k: engine_lib.init_serving_params(cfg, k),
+        jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    assert {a.dtype for a in jax.tree.leaves(params)} == {jnp.dtype(jnp.bfloat16)}
+    spec = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    pool = spec((cfg.n_layers, NB, BS, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
+    vec = spec((b,), I32)
+    tokens = spec((b, 1), I32)
+    if path == "tables":
+        lowered = paged_decode_step.lower(cfg, params, pool, pool,
+                                          spec((b, mb), I32), vec, tokens)
+    else:
+        lowered = paged_decode_step_fused.lower(
+            cfg, params, pool, pool, spec((64, 8, mb, 2), U32),
+            spec((64,), I32), vec, vec, vec, tokens)
+    compiled = lowered.compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used <= V5E_HBM_BYTES, (
+        f"decode step needs {used / 2**30:.2f} GiB of {V5E_HBM_BYTES / 2**30} GiB")
