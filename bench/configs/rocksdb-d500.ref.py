@@ -1,0 +1,106 @@
+"""Plain reference of the ``rocksdb-d500`` deployment: the benchmark's own
+record of which layer wrote which cluster with what payload, and what a
+read of any cluster must return. Imports nothing of the program.
+
+The layout of the data is the same for every seed: each layer owns the
+same number of live clusters, and the owner of the cluster at each
+popularity rank is fixed. The seed chooses which cluster ids hold the
+ranks, the payloads and the request stream. So every seed asks the same
+work of a resolver whose cost follows the owning layer.
+"""
+
+from __future__ import annotations
+
+import numpy as np
+
+#: Seed of the rank -> owning-layer layout, the same for every run.
+LAYOUT_SEED = 20052206
+
+_M = np.uint32(0xFFFFFFFF)
+
+
+def seed_words(seed: int) -> tuple[int, int]:
+    s = int(seed) % (1 << 64)
+    return s & 0xFFFFFFFF, s >> 32
+
+
+def _fmix32(h: np.ndarray) -> np.ndarray:
+    h = h ^ (h >> np.uint32(16))
+    h = h * np.uint32(0x85EBCA6B)
+    h = h ^ (h >> np.uint32(13))
+    h = h * np.uint32(0xC2B2AE35)
+    return h ^ (h >> np.uint32(16))
+
+
+def payload_bits(seed: int, tenant, page, n_words: int) -> np.ndarray:
+    """The uint32 bit patterns of the payload of ``(tenant, page)``:
+    (..., n_words), each a float32 in [1, 2)."""
+    s0, s1 = seed_words(seed)
+    t = np.asarray(tenant, np.uint32)[..., None]
+    p = np.asarray(page, np.uint32)[..., None]
+    j = np.arange(n_words, dtype=np.uint32)
+    with np.errstate(over="ignore"):
+        h = (np.uint32(s0) ^ (t * np.uint32(0x9E3779B1))
+             ^ (p * np.uint32(0x85EBCA77)) ^ (j * np.uint32(0xC2B2AE3D))
+             ^ (np.uint32(s1) * np.uint32(0x27D4EB2F)))
+        h = _fmix32(h)
+    return (h >> np.uint32(9)) | np.uint32(0x3F800000)
+
+
+def rank_owners(cfg: dict, key_order: str) -> np.ndarray:
+    """Owning layer of the live cluster at each popularity rank (rank 0
+    is the hottest). Every layer owns ``live / depth`` clusters (to
+    within one)."""
+    n_live, depth = cfg["live_clusters_per_disk"], cfg["chain_depth"]
+    owners = np.arange(n_live, dtype=np.int32) % depth
+    if key_order == "seeded_permutation":
+        return np.random.default_rng(LAYOUT_SEED).permutation(owners)
+    if key_order == "newest_layer_first":
+        return np.sort(owners)[::-1].copy()
+    raise ValueError(f"unknown key_order {key_order!r}")
+
+
+class Record:
+    """Which cluster each rank is, per disk, and who wrote it."""
+
+    def __init__(self, cfg: dict, seed: int, key_order: str):
+        t, p = cfg["tenants"], cfg["l2_entries_per_layer"]
+        n_live = cfg["live_clusters_per_disk"]
+        rng = np.random.default_rng([int(seed) % (1 << 64), 7])
+        self.cfg = cfg
+        self.seed = int(seed)
+        self.owners = rank_owners(cfg, key_order)                 # (L,)
+        self.clusters = np.stack([rng.permutation(p)[:n_live]
+                                  for _ in range(t)]).astype(np.int32)
+        self.depth = cfg["chain_depth"]
+        self.scalable = np.array([f == "scalable"
+                                  for f in cfg["tenant_formats"]])
+
+    def owner_map(self) -> np.ndarray:
+        """(T, P) int32: owning layer of every cluster, -1 if unwritten."""
+        t, p = self.clusters.shape[0], self.cfg["l2_entries_per_layer"]
+        out = np.full((t, p), -1, np.int32)
+        for i in range(t):
+            out[i, self.clusters[i]] = self.owners
+        return out
+
+    def expected(self, ranks: np.ndarray):
+        """What a read of the clusters at ``ranks`` ((T, B)) returns:
+        ``(ids, owner, payload bits)``, each page found."""
+        ids = np.take_along_axis(self.clusters, ranks, axis=1)
+        owner = self.owners[ranks]
+        tenant = np.broadcast_to(np.arange(ranks.shape[0])[:, None],
+                                 ranks.shape)
+        bits = payload_bits(self.seed, tenant, ids, self.cfg["payload_words"])
+        return ids, owner, bits
+
+
+def mismatched_pages(record: Record, ranks: np.ndarray, data: np.ndarray,
+                     found: np.ndarray, owner: np.ndarray) -> int:
+    """Pages of one read whose data, ``found`` or owner differ from the
+    record: payload compared bit for bit."""
+    _, want_owner, want_bits = record.expected(ranks)
+    got = np.ascontiguousarray(data, np.float32).view(np.uint32)
+    bad = (got != want_bits).any(axis=-1) | ~found.astype(bool)
+    bad |= owner != want_owner
+    return int(bad.sum())
