@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import chain as chain_lib
 from repro.core import format as fmt
 from repro.core import resolve as resolve_lib
@@ -411,7 +412,6 @@ def _uses_kernels(spec: FleetSpec, method: str) -> bool:
             or (method == "auto" and _kernel_layout_ok(spec)))
 
 
-@partial(jax.jit, static_argnames=("method",))
 def read(fleet: ChainFleet, page_ids: jax.Array, *, method: str = "auto"):
     """Batched whole-page read across the fleet.
 
@@ -430,17 +430,41 @@ def read(fleet: ChainFleet, page_ids: jax.Array, *, method: str = "auto"):
         ``store.read``. Kernel methods gather through the stacked Pallas
         gather of ``kernels/cow_gather``; jnp methods use the shared
         ``store.gather_pages`` helper. Both are bit-identical.
+
+    The call opens the host span ``snapstore.fleet.read`` around the
+    enqueue of the compiled program (module ``jit_read``), with the pages
+    asked for (T×B), the table entries the resolver resolves (T×n_pages on
+    the kernel path, T×B on the jnp paths) and the method. ``read.lower``
+    lowers that program ahead of time, as on any jitted function.
     """
+    t, b = np.shape(page_ids)
+    per_tenant = (fleet.spec.n_pages if _uses_kernels(fleet.spec, method)
+                  else b)
+    with obs.span("fleet.read", pages=t * b, resolved=t * per_tenant,
+                  method=method):
+        return _read(fleet, page_ids, method=method)
+
+
+def _read_program(fleet: ChainFleet, page_ids: jax.Array, *, method: str):
+    """The device program of ``read``: resolve, then gather, each under
+    its own named scope (``fleet.resolve``, ``fleet.gather``)."""
     from repro.core import store  # local import: store is the public API layer
 
-    res = get_resolver(method)(fleet, page_ids)
-    if _uses_kernels(fleet.spec, method):
-        # cold hits address the host tier — mask them like ZERO clusters
-        # (read_tiered fills them from the TieredStore afterwards)
-        ok = res.found & ~res.zero & ~res.cold
-        rows = jnp.where(ok, res.ptr, 0).astype(jnp.int32)
-        return cow_ops.gather_fleet(fleet.pool, rows, ok), res
-    return store.gather_pages(fleet.pool, res), res
+    with jax.named_scope("fleet.resolve"):
+        res = get_resolver(method)(fleet, page_ids)
+    with jax.named_scope("fleet.gather"):
+        if _uses_kernels(fleet.spec, method):
+            # cold hits address the host tier — mask them like ZERO
+            # clusters (read_tiered fills them from the TieredStore)
+            ok = res.found & ~res.zero & ~res.cold
+            rows = jnp.where(ok, res.ptr, 0).astype(jnp.int32)
+            return cow_ops.gather_fleet(fleet.pool, rows, ok), res
+        return store.gather_pages(fleet.pool, res), res
+
+
+_read_program.__name__ = "read"     # the compiled module is ``jit_read``
+_read = jax.jit(_read_program, static_argnames=("method",))
+read.lower = _read.lower
 
 
 def materialize(fleet: ChainFleet, *, method: str = "auto") -> jax.Array:

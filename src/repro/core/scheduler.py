@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.core import fleet as fleet_lib
 from repro.core.fleet import ChainFleet
 
@@ -280,7 +281,19 @@ class MaintenanceScheduler:
         the device page budget, stream at most K tenants, compact the
         ones wedged on overflow. Returns a report of the work done.
         A drained (or fully parked) queue ticks for free: one
-        tenant_stats sync, no streaming, no repack, no transfers."""
+        tenant_stats sync, no streaming, no repack, no transfers.
+
+        The slice is the host span ``snapstore.maintenance.tick``, with
+        the report's counts as its arguments."""
+        with obs.span("maintenance.tick") as span:
+            rep = self._tick()
+            span.set_metadata(streamed=len(rep["streamed"]),
+                              compacted=int(rep["compacted"]),
+                              rows_demoted=rep["rows_demoted"],
+                              backlog=rep["backlog"])
+            return rep
+
+    def _tick(self) -> dict:
         st0 = fleet_lib.tenant_stats(self.fleet)
         cands = self.candidates(st0)
         picks = cands[: self.max_tenants_per_tick]

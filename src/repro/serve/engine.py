@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core import fleet as fleet_lib
 from repro.core.golden import PrefixTrie
@@ -168,18 +169,21 @@ class Engine:
         trie hit this is the ordinary full prefill.
         """
         toks = [int(t) for t in np.asarray(prompt_tokens).reshape(-1)]
-        depth, gsid = self._trie.longest_prefix(toks)
-        if gsid is not None:
-            self.golden_hits += 1
-            sid = self.kv.fork(gsid)
-            suffix = toks[depth:]
-            nxt = (self._suffix_prefill(sid, suffix) if suffix
-                   else self._golden_info[gsid][1])
-            self.active[sid] = [nxt]
+        with obs.span("engine.admit", tokens=len(toks)) as span:
+            depth, gsid = self._trie.longest_prefix(toks)
+            if gsid is not None:
+                self.golden_hits += 1
+                sid = self.kv.fork(gsid)
+                span.set_metadata(sid=sid, path="golden")
+                suffix = toks[depth:]
+                nxt = (self._suffix_prefill(sid, suffix) if suffix
+                       else self._golden_info[gsid][1])
+                self.active[sid] = [nxt]
+                return sid
+            sid, first = self._prefill_seq(prompt_tokens)
+            span.set_metadata(sid=sid, path="full")
+            self.active[sid] = [first]
             return sid
-        sid, first = self._prefill_seq(prompt_tokens)
-        self.active[sid] = [first]
-        return sid
 
     def _suffix_prefill(self, sid: int, tokens) -> int:
         """Push a prompt suffix through ONE chunked device dispatch
@@ -336,9 +340,10 @@ class Engine:
             # stamps this step's write slots, then the decode step reads
             # K/V straight through the stacked fleet index (the chain
             # walk runs inside the attention plane).
-            plan = self.kv.prepare_step_fused(
-                sids, pad_to=pad_to, pad_block=self._pad_block
-            )
+            with obs.span("kv.prepare", rows=len(sids)):
+                plan = self.kv.prepare_step_fused(
+                    sids, pad_to=pad_to, pad_block=self._pad_block
+                )
             logits, pk, pv = paged_decode_step_fused(
                 self.cfg, self.params, self.kv.pool_k, self.kv.pool_v,
                 plan.l2, plan.chain_lengths, plan.tenants, plan.lengths,
@@ -351,24 +356,27 @@ class Engine:
             # ships once. A lone sequence (suffix admission) takes the
             # narrow single-row resolve — O(C·P), not O(T·C·P) — so
             # admission latency stays flat as the fleet fills.
-            if len(sids) == 1:
-                tables, lengths = self.kv.prepare_step_single(
-                    sids[0], pad_to=pad_to, pad_block=self._pad_block
-                )
-            else:
-                tables, lengths = self.kv.prepare_step(
-                    sids, pad_to=pad_to, pad_block=self._pad_block
-                )
+            with obs.span("kv.prepare", rows=len(sids)):
+                if len(sids) == 1:
+                    tables, lengths = self.kv.prepare_step_single(
+                        sids[0], pad_to=pad_to, pad_block=self._pad_block
+                    )
+                else:
+                    tables, lengths = self.kv.prepare_step(
+                        sids, pad_to=pad_to, pad_block=self._pad_block
+                    )
             logits, pk, pv = paged_decode_step(
                 self.cfg, self.params, self.kv.pool_k, self.kv.pool_v,
                 tables, lengths, jnp.asarray(tok_col),
             )
-        self.kv.commit_pools(pk, pv)
+        with obs.span("kv.commit"):
+            self.kv.commit_pools(pk, pv)
         self.last_logits = logits
         out = {}
         # the sampling boundary: greedy argmax must reach the host to
         # extend python-side sequences — the one designed sync in step()
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))  # fleetlint: disable=FL002
+        with obs.span("engine.sample", rows=len(sids)):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))  # fleetlint: disable=FL002
         for i, sid in enumerate(sids):
             self.kv.advance(sid)
             out[sid] = int(nxt[i])
@@ -378,16 +386,19 @@ class Engine:
         """Decode one token for every active sequence — one fleet-batched
         device dispatch: stacked block tables, padded to a size bucket."""
         sids = sorted(self.active)
-        if not sids:
-            # an idle engine is the cheapest time for background work —
-            # keep draining the maintenance backlog while polling
+        with obs.span("engine.step", rows=len(sids),
+                      padded_rows=self._bucket(len(sids)) if sids else 0):
+            if not sids:
+                # an idle engine is the cheapest time for background
+                # work — keep draining the maintenance backlog while
+                # polling
+                self._maintain()
+                return {}
+            out = self._decode(sids, [self.active[s][-1] for s in sids])
+            for sid, tok in out.items():
+                self.active[sid].append(tok)
             self._maintain()
-            return {}
-        out = self._decode(sids, [self.active[s][-1] for s in sids])
-        for sid, tok in out.items():
-            self.active[sid].append(tok)
-        self._maintain()
-        return out
+            return out
 
     def _maintain(self) -> None:
         """One budgeted maintenance slice between decode steps: stream/GC
