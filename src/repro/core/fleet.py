@@ -365,12 +365,14 @@ def resolve_auto(fleet: ChainFleet, page_ids: jax.Array):
     """Mixed-image resolution (direct where trusted, walk otherwise).
 
     Implementation is chosen statically at trace time: the stacked Pallas
-    kernels when the layout qualifies (``_kernel_layout_ok``), the
-    vmapped jnp gather otherwise. Both produce bit-identical results —
-    only the data plane differs. Off-TPU the kernels run in interpret
-    mode (so CI exercises them), which is slower than the vmapped gather;
-    latency-sensitive CPU callers with lane-aligned layouts should pass
-    an explicit jnp method (``"vanilla"``/``"direct"``/``"gather"``).
+    kernels when the layout qualifies (``_kernel_layout_ok``; of those,
+    the batch kernel where the batch is narrow beside the table,
+    ``resolve.batch_path_ok``), the vmapped jnp gather otherwise. All
+    produce bit-identical results — only the data plane differs. Off-TPU
+    the kernels run in interpret mode (so CI exercises them), which is
+    slower than the vmapped gather; latency-sensitive CPU callers with
+    lane-aligned layouts should pass an explicit jnp method
+    (``"vanilla"``/``"direct"``/``"gather"``).
     """
     if _kernel_layout_ok(fleet.spec):
         return resolve_lib.resolve_auto_stacked(fleet.l2, fleet.length,
@@ -434,12 +436,15 @@ def read(fleet: ChainFleet, page_ids: jax.Array, *, method: str = "auto"):
     The call opens the host span ``snapstore.fleet.read`` around the
     enqueue of the compiled program (module ``jit_read``), with the pages
     asked for (T×B), the table entries the resolver resolves (T×n_pages on
-    the kernel path, T×B on the jnp paths) and the method. ``read.lower``
-    lowers that program ahead of time, as on any jitted function.
+    the whole-table kernel path, T×B on the jnp paths and where ``"auto"``
+    takes the batch kernel, ``resolve.batch_path_ok``) and the method.
+    ``read.lower`` lowers that program ahead of time, as on any jitted
+    function.
     """
     t, b = np.shape(page_ids)
-    per_tenant = (fleet.spec.n_pages if _uses_kernels(fleet.spec, method)
-                  else b)
+    whole_table = _uses_kernels(fleet.spec, method) and not (
+        method == "auto" and resolve_lib.batch_path_ok(fleet.spec.n_pages, b))
+    per_tenant = fleet.spec.n_pages if whole_table else b
     with obs.span("fleet.read", pages=t * b, resolved=t * per_tenant,
                   method=method):
         return _read(fleet, page_ids, method=method)

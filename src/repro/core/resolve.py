@@ -28,9 +28,11 @@ A second implementation of each strategy lives in the Pallas kernels of
 ``kernels/chain_resolve``: the ``resolve_*_stacked`` functions here run
 them over the whole stacked (T, C, P) fleet layout in one kernel launch
 (compiled on TPU, interpret mode elsewhere — CI exercises the kernel
-path on CPU). Single chains reach the same kernels through the
-``"pallas_vanilla"``/``"pallas_direct"`` registry entries, which view a
-chain as a one-tenant fleet. See ``docs/kernels.md``.
+path on CPU); ``resolve_auto_stacked`` resolves a batch that is narrow
+beside the table with one kernel that reads only the requested pages'
+tile columns (``batch_path_ok``). Single chains reach the same kernels
+through the ``"pallas_vanilla"``/``"pallas_direct"`` registry entries,
+which view a chain as a one-tenant fleet. See ``docs/kernels.md``.
 """
 
 from __future__ import annotations
@@ -43,6 +45,7 @@ import jax.numpy as jnp
 from repro.core import format as fmt
 from repro.core.chain import Chain
 from repro.kernels.chain_resolve import ops as _kernel_ops
+from repro.kernels.common import LANES
 
 
 class ResolveResult(NamedTuple):
@@ -166,20 +169,8 @@ _TABLE_RESOLVERS = {
 # -- Pallas-kernel resolvers over the stacked (T, C, P, 2) fleet layout ------
 
 
-def resolve_vanilla_stacked(l2: jax.Array, lengths: jax.Array,
-                            page_ids: jax.Array) -> ResolveResult:
-    """Kernel-backed first-hit walk for a whole fleet in one launch.
-
-    ``l2``: (T, C, n_pages, 2) uint32 stacked tables; ``lengths``: (T,);
-    ``page_ids``: (T, B). The kernel resolves every tenant's *full* page
-    table (the walk cost is amortized across the read batch); the batch's
-    owners/pointers are then a cheap per-tenant gather. Results are
-    bit-identical to ``resolve_vanilla_tables`` vmapped over tenants.
-    """
-    ids = page_ids.astype(jnp.int32)
-    owner_map, hit_map = _kernel_ops.resolve_vanilla_fleet(l2[..., 0], lengths)
-    owner = jnp.take_along_axis(owner_map, ids, axis=1)
-    hit = jnp.take_along_axis(hit_map, ids, axis=1)
+def _walk_result(owner, hit, lengths) -> ResolveResult:
+    """A first-hit walk's (T, B) owners and owning word0s as a result."""
     found = owner >= 0
     ln = lengths.astype(jnp.int32)[:, None]
     return ResolveResult(
@@ -193,6 +184,35 @@ def resolve_vanilla_stacked(l2: jax.Array, lengths: jax.Array,
     )
 
 
+def _direct_result(owner, h0, h1) -> ResolveResult:
+    """The active layer's (T, B) raw words (and bfi owners) as a result."""
+    alloc = (h0 & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
+    return ResolveResult(
+        owner=owner.astype(jnp.int32),
+        ptr=h0 & jnp.uint32(fmt.PTR_MASK),
+        found=alloc & ((h1 & jnp.uint32(fmt.FLAG_BFI_VALID)) != 0),
+        zero=((h0 & jnp.uint32(fmt.FLAG_ZERO)) != 0) & alloc,
+        lookups=jnp.ones(owner.shape, jnp.int32),
+        cold=((h0 & jnp.uint32(fmt.FLAG_COLD)) != 0) & alloc,
+    )
+
+
+def resolve_vanilla_stacked(l2: jax.Array, lengths: jax.Array,
+                            page_ids: jax.Array) -> ResolveResult:
+    """Kernel-backed first-hit walk for a whole fleet in one launch.
+
+    ``l2``: (T, C, n_pages, 2) uint32 stacked tables; ``lengths``: (T,);
+    ``page_ids``: (T, B). The kernel resolves every tenant's *full* page
+    table (the walk cost is amortized across the read batch); the batch's
+    owners/pointers are then a cheap per-tenant gather. Results are
+    bit-identical to ``resolve_vanilla_tables`` vmapped over tenants.
+    """
+    ids = page_ids.astype(jnp.int32)
+    owner_map, hit_map = _kernel_ops.resolve_vanilla_fleet(l2[..., 0], lengths)
+    return _walk_result(jnp.take_along_axis(owner_map, ids, axis=1),
+                        jnp.take_along_axis(hit_map, ids, axis=1), lengths)
+
+
 def resolve_direct_stacked(l2: jax.Array, lengths: jax.Array,
                            page_ids: jax.Array) -> ResolveResult:
     """Kernel-backed direct access for a whole fleet in one launch.
@@ -203,29 +223,38 @@ def resolve_direct_stacked(l2: jax.Array, lengths: jax.Array,
     ``resolve_direct_tables`` vmapped over tenants.
     """
     ids = page_ids.astype(jnp.int32)
-    owner_map, h0_map, h1_map = _kernel_ops.resolve_direct_fleet(
-        l2[..., 0], l2[..., 1], lengths
-    )
-    owner = jnp.take_along_axis(owner_map, ids, axis=1)
-    h0 = jnp.take_along_axis(h0_map, ids, axis=1)
-    h1 = jnp.take_along_axis(h1_map, ids, axis=1)
-    alloc = (h0 & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
-    return ResolveResult(
-        owner=owner.astype(jnp.int32),
-        ptr=h0 & jnp.uint32(fmt.PTR_MASK),
-        found=alloc & ((h1 & jnp.uint32(fmt.FLAG_BFI_VALID)) != 0),
-        zero=((h0 & jnp.uint32(fmt.FLAG_ZERO)) != 0) & alloc,
-        lookups=jnp.ones_like(ids),
-        cold=((h0 & jnp.uint32(fmt.FLAG_COLD)) != 0) & alloc,
-    )
+    maps = _kernel_ops.resolve_direct_fleet(l2[..., 0], l2[..., 1], lengths)
+    return _direct_result(*(jnp.take_along_axis(m, ids, axis=1)
+                            for m in maps))
+
+
+def batch_path_ok(n_pages: int, batch: int) -> bool:
+    """Shape rule of ``resolve_auto_stacked``: resolve only the batch's
+    pages where the page axis is a 128-lane multiple and the batch's tile
+    columns cover less than the table (``B * 128 < P``); a batch as wide
+    as the table (a full grid) is cheaper as one whole-table pass."""
+    return n_pages % LANES == 0 and batch * LANES < n_pages
 
 
 def resolve_auto_stacked(l2: jax.Array, lengths: jax.Array,
                          page_ids: jax.Array) -> ResolveResult:
-    """Kernel-backed mixed-image resolution: both kernels, then the same
-    ``combine_auto`` trust pick as the jnp auto resolver."""
-    direct = resolve_direct_stacked(l2, lengths, page_ids)
-    walk = resolve_vanilla_stacked(l2, lengths, page_ids)
+    """Kernel-backed mixed-image resolution, then the same
+    ``combine_auto`` trust pick as the jnp auto resolver.
+
+    Where ``batch_path_ok`` holds, one kernel reads only the requested
+    pages' tile columns of the index, in place, and yields both the walk
+    and the direct result of each; otherwise both whole-table kernels
+    run and the batch is gathered out of their maps. Bit-identical."""
+    if batch_path_ok(l2.shape[2], page_ids.shape[1]):
+        ids = page_ids.astype(jnp.int32)
+        owner, hit, h0, h1 = _kernel_ops.resolve_batch_fleet(l2, lengths, ids)
+        alloc = (h0 & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0
+        bfi = (h1 & jnp.uint32(fmt.BFI_MASK)).astype(jnp.int32)
+        direct = _direct_result(jnp.where(alloc, bfi, -1), h0, h1)
+        walk = _walk_result(owner, hit, lengths)
+    else:
+        direct = resolve_direct_stacked(l2, lengths, page_ids)
+        walk = resolve_vanilla_stacked(l2, lengths, page_ids)
     return combine_auto(direct.found, direct, walk)
 
 

@@ -9,7 +9,9 @@ tenant axis, per-tenant chain ``length`` is prefetched as a scalar (the
 direct kernel's BlockSpec index_map stages *only* the sublane group
 holding each tenant's active layer), and the fleet kernels consume the packed L2 words of
 ``core.format`` directly — the kernel reads the actual table format, as
-the paper's sQemu data plane does.
+the paper's sQemu data plane does. ``resolve_batch_fleet_pallas``
+resolves only a batch of requested pages, reading each one's 128-page
+tile column of the packed index in place.
 
 See ``docs/kernels.md`` for the full cost model, tiling constraints
 (pages on the 128-lane axis, chain axis in sublanes) and the
@@ -26,9 +28,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import format as fmt
-from repro.kernels.common import SUBLANES, first_hit_walk
+from repro.kernels.common import LANES, SUBLANES, first_hit_walk
 
 PAGE_TILE = 512  # lanes per grid step (4 × 128)
+WALK_UNROLL = 8  # layers per loop step of the batch kernel's walk
 
 
 def _vanilla_kernel(length_ref, alloc_ref, ptr_ref, owner_ref, out_ptr_ref):
@@ -200,3 +203,96 @@ def resolve_direct_fleet_pallas(w0, w1, lengths, *, interpret: bool = True):
     )(lengths, (lengths - 1) // sub, w0.astype(jnp.uint32),
       w1.astype(jnp.uint32))
     return owner[:, 0], h0[:, 0], h1[:, 0]
+
+
+def _put_lane(out_ref, b, value):
+    """Write the (1, 1) ``value`` into lane ``b`` of the resident row
+    ``out_ref``, leaving its other lanes as they are."""
+    at_b = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1) == b
+    out_ref[...] = jnp.where(at_b, value, out_ref[...])
+
+
+def _batch_fleet_kernel(length_ref, tile_ref, lane_ref, col_ref, owner_ref,
+                        hit_ref, h0_ref, h1_ref):
+    t, b = pl.program_id(0), pl.program_id(1)
+    n_layers = col_ref.shape[0]
+    length = length_ref[t]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    at_page = lanes == lane_ref[t, b]
+
+    # the page's lane of ``row``, as a (1, 1) vector of ``dtype`` (summed
+    # as int32: Mosaic reduces no unsigned type)
+    def pick(row, dtype):
+        bits = jax.lax.bitcast_convert_type(row, jnp.int32)
+        lane = jnp.sum(jnp.where(at_page, bits, 0), axis=1, keepdims=True)
+        return jax.lax.bitcast_convert_type(lane, dtype)
+
+    # the walk: the highest live allocated layer of every lane in the
+    # column, found by one ascending pass in which a later hit wins
+    def layer(i, carry):
+        owner, hit = carry
+        w0 = col_ref[i, pl.ds(0, 1), :]
+        take = ((w0 & jnp.uint32(fmt.FLAG_ALLOCATED)) != 0) & (i < length)
+        return jnp.where(take, i, owner), jnp.where(take, w0, hit)
+
+    def group(g, carry):  # WALK_UNROLL layers per loop step
+        for j in range(WALK_UNROLL):
+            carry = layer(g * WALK_UNROLL + j, carry)
+        return carry
+
+    n_groups = n_layers // WALK_UNROLL
+    carry = jax.lax.fori_loop(0, n_groups, group,
+                              (jnp.full((1, LANES), -1, jnp.int32),
+                               jnp.zeros((1, LANES), jnp.uint32)))
+    for i in range(n_groups * WALK_UNROLL, n_layers):
+        carry = layer(i, carry)
+    owner, hit = carry
+    active = col_ref[jnp.clip(length - 1, 0, n_layers - 1)]      # (2, 128)
+    _put_lane(owner_ref, b, pick(owner, jnp.int32))
+    _put_lane(hit_ref, b, pick(hit, jnp.uint32))
+    _put_lane(h0_ref, b, pick(active[0:1], jnp.uint32))
+    _put_lane(h1_ref, b, pick(active[1:2], jnp.uint32))
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def resolve_batch_fleet_pallas(l2t, lengths, ids, *, interpret: bool = True):
+    """Walk and direct results of the requested pages only.
+
+    ``l2t``: (T, C, 2, P) uint32 — the packed L2 index with the entry's
+    two words second-minor (``jnp.swapaxes(l2, 2, 3)``: on a TPU this is
+    the index's own HBM layout, so the view costs no copy); ``lengths``:
+    (T,) int32; ``ids``: (T, B) int32 page ids in ``[0, P)``, P a
+    multiple of 128.
+
+    Grid (T, B): each step stages the requested page's 128-page tile
+    column across the whole chain — both words of every layer, picked by
+    the index_map from the prefetched id — and reduces it to that page's
+    results. Two consecutive steps that ask for the same tile fetch it
+    once.
+
+    Returns ``(owner, hit, h0, h1)``, each (T, B): the first-hit walk's
+    owning layer (-1 if absent) and that layer's word0 (0 if absent), as
+    ``resolve_vanilla_fleet_pallas`` gives them, and the active layer's
+    raw words, as ``resolve_direct_fleet_pallas`` does.
+    """
+    t, c, _, p = l2t.shape
+    b = ids.shape[1]
+    ids = ids.astype(jnp.int32)
+    # the tile is clamped into the index: a DMA is not bounds-checked
+    tiles = jnp.clip(ids // LANES, 0, p // LANES - 1)
+    col = pl.BlockSpec((None, c, 2, LANES),
+                       lambda ti, bi, ln, tile, lane: (ti, 0, 0, tile[ti, bi]))
+    out = pl.BlockSpec((None, 1, b), lambda ti, bi, *prefetch: (ti, 0, 0))
+    res = pl.pallas_call(
+        _batch_fleet_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(t, b),
+            in_specs=[col],
+            out_specs=[out] * 4,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((t, 1, b), d) for d in
+                   (jnp.int32, jnp.uint32, jnp.uint32, jnp.uint32)],
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), tiles, ids % LANES, l2t.astype(jnp.uint32))
+    return tuple(r[:, 0] for r in res)

@@ -10,9 +10,11 @@ suite compares against).
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels.chain_resolve import ref
 from repro.kernels.chain_resolve.chain_resolve import (
+    resolve_batch_fleet_pallas,
     resolve_direct_fleet_pallas,
     resolve_direct_pallas,
     resolve_vanilla_fleet_pallas,
@@ -63,3 +65,11 @@ def resolve_direct_fleet(w0, w1, lengths):
     owner, h0, h1 = resolve_direct_fleet_pallas(w0_p, w1_p, lengths,
                                                 interpret=not _on_tpu())
     return owner[:, :n], h0[:, :n], h1[:, :n]
+
+
+def resolve_batch_fleet(l2, lengths, ids):
+    """Walk and direct results of the (T, B) pages ``ids`` only, read from
+    the packed (T, C, P, 2) index in place. Always the Pallas kernel
+    (interpret off-TPU); P must be a multiple of 128."""
+    return resolve_batch_fleet_pallas(jnp.swapaxes(l2, 2, 3), lengths, ids,
+                                      interpret=not _on_tpu())

@@ -16,6 +16,8 @@ pinned against the same single-chain jnp oracle, so the kernel and gather
 implementations cannot drift apart.
 """
 
+import re
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -390,3 +392,79 @@ def test_free_tenant_empty_id_list_is_noop():
     out = fleet.free_tenant(fl, [])
     np.testing.assert_array_equal(np.asarray(out.lease_count),
                                   np.asarray(fl.lease_count))
+
+
+def _narrow_fleet(n_pages=2048):
+    """Two tenants (scalable, vanilla), two layers, pages written in both,
+    in one layer only, and never."""
+    spec = fleet.FleetSpec(
+        n_tenants=2, n_pages=n_pages, page_size=PAGE, max_chain=4,
+        pool_capacity=256, lease_quantum=32, l2_per_table=32,
+    )
+    fl = fleet.create(spec, scalable=jnp.asarray([True, False]))
+    ids = jnp.asarray([[0, 5, 130, n_pages - 1]] * 2, jnp.int32)
+    fl = fleet.write(fl, ids, jnp.ones((2, 4, PAGE)))
+    fl = fleet.snapshot(fl)
+    return fleet.write(fl, ids[:, :2] + 1000,
+                       2.0 * jnp.ones((2, 2, PAGE))), spec
+
+
+def _kernels_in(lowered):
+    """The resolve kernels named in the ops of a compiled program."""
+    names = " ".join(re.findall(r'op_name="([^"]*)"',
+                                lowered.compile().as_text()))
+    return {k for k in ("resolve_batch_fleet_pallas",
+                        "resolve_vanilla_fleet_pallas",
+                        "resolve_direct_fleet_pallas") if k in names}
+
+
+@pytest.mark.parametrize("b", [8, 2048])
+def test_auto_read_resolves_only_the_batch_when_narrow(b):
+    """On both sides of the shape rule (B * 128 < P), method="auto" reads
+    what method="vanilla" reads, bit for bit (the owner aside: a scalable
+    tenant's is its entry's bfi, not the layer a snapshot copied it to),
+    and resolves like the vmapped jnp auto; a narrow batch takes the batch kernel, a full grid
+    the two whole-table kernels."""
+    import jax
+
+    from repro.core import resolve as resolve_lib
+
+    fl, spec = _narrow_fleet()
+    rng = np.random.default_rng(b)
+    ids = (np.stack([rng.permutation(spec.n_pages)[:b] for _ in range(2)])
+           if b < spec.n_pages else np.tile(np.arange(b), (2, 1)))
+    ids[:, :6] = [0, 5, 130, 1000, 1005, spec.n_pages - 1]
+    ids = jnp.asarray(ids, jnp.int32)
+    narrow = resolve_lib.batch_path_ok(spec.n_pages, b)
+    assert narrow == (b == 8)
+    assert _kernels_in(fleet.read.lower(fl, ids, method="auto")) == (
+        {"resolve_batch_fleet_pallas"} if narrow else
+        {"resolve_vanilla_fleet_pallas", "resolve_direct_fleet_pallas"})
+    data, res = fleet.read(fl, ids, method="auto")
+    vdata, vres = fleet.read(fl, ids, method="vanilla")
+    np.testing.assert_array_equal(np.asarray(data), np.asarray(vdata))
+    for field in ("ptr", "found", "zero", "cold"):
+        np.testing.assert_array_equal(np.asarray(getattr(res, field)),
+                                      np.asarray(getattr(vres, field)),
+                                      err_msg=field)
+    want = jax.vmap(resolve_lib.get_table_resolver("auto"))(
+        fl.l2, fl.length, ids)
+    for field in res._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(res, field)),
+                                      np.asarray(getattr(want, field)),
+                                      err_msg=f"auto field {field}")
+
+
+def test_full_grid_resolves_keep_the_whole_table_path():
+    """materialize and the serving plane's table resolve pass B == P: they
+    take the whole-table kernels, and materialize reads as the walk."""
+    from repro.kvcache import paged
+
+    fl, spec = _narrow_fleet(256)
+    grid = jnp.broadcast_to(jnp.arange(spec.n_pages, dtype=jnp.int32)[None],
+                            (2, spec.n_pages))
+    assert _kernels_in(paged._fleet_tables.lower(fl, grid, "auto")) == {
+        "resolve_vanilla_fleet_pallas", "resolve_direct_fleet_pallas"}
+    np.testing.assert_array_equal(
+        np.asarray(fleet.materialize(fl, method="auto")),
+        np.asarray(fleet.materialize(fl, method="vanilla")))

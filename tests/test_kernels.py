@@ -6,10 +6,12 @@ import numpy as np
 import pytest
 
 from repro.core import format as fmt
+from repro.core import resolve as resolve_lib
 from repro.kernels.chain_resolve import ref as cr_ref
 from repro.kernels.chain_resolve.chain_resolve import (
-    resolve_direct_fleet_pallas, resolve_direct_pallas,
-    resolve_vanilla_fleet_pallas, resolve_vanilla_pallas)
+    resolve_batch_fleet_pallas, resolve_direct_fleet_pallas,
+    resolve_direct_pallas, resolve_vanilla_fleet_pallas,
+    resolve_vanilla_pallas)
 from repro.kernels.cow_gather import ref as cg_ref
 from repro.kernels.cow_gather.cow_gather import gather_fleet_pallas, gather_pallas
 from repro.kernels.paged_attention import ops as pa_ops
@@ -82,6 +84,72 @@ def test_chain_resolve_direct_fleet_sweep(t, c, p):
     r2 = resolve_direct_fleet_pallas(w0, w1, lengths, interpret=True)
     for a, b in zip(r1, r2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _batch_ids(key, kind, t, b, p):
+    """(T, B) page ids of one kind: random (duplicates likely), several
+    (and repeated) ids inside one 128-page tile, or all in the last tile."""
+    if kind == "random":
+        return jax.random.randint(key, (t, b), 0, p)
+    if kind == "one_tile":
+        ids = 256 % p + jax.random.randint(key, (t, b), 0, 16)
+        return ids.at[:, -1].set(ids[:, 0])
+    return jax.random.randint(key, (t, b), p - 128, p)
+
+
+@pytest.mark.parametrize("t,c,p,b", [(1, 1, 128, 1), (3, 7, 256, 8),
+                                     (2, 16, 640, 5), (4, 9, 384, 16)])
+@pytest.mark.parametrize("kind", ["random", "one_tile", "last_tile"])
+@pytest.mark.parametrize("density", [0.05, 0.6])
+def test_chain_resolve_batch_fleet_sweep(t, c, p, b, kind, density):
+    key = jax.random.fold_in(KEY, t * c * p + b)
+    w0, w1 = _packed_fleet_words(key, t, c, p, density)
+    ids = _batch_ids(jax.random.fold_in(key, 7), kind, t, b, p)
+    # some requested pages unwritten in every layer
+    unwritten = jax.random.uniform(jax.random.fold_in(key, 8), (t, p)) < 0.3
+    unwritten = unwritten & jnp.zeros((t, p), bool).at[
+        jnp.arange(t)[:, None], ids].set(True)
+    w0 = jnp.where(unwritten[:, None], jnp.uint32(0), w0)
+    w1 = jnp.where(unwritten[:, None], jnp.uint32(0), w1)
+    # ragged lengths below max_chain, a length-1 tenant among them
+    lengths = jax.random.randint(jax.random.fold_in(key, 9), (t,), 1, c + 1)
+    lengths = lengths.at[0].set(1)
+    owner, hit = cr_ref.resolve_vanilla_fleet_ref(w0, lengths)
+    _, h0, h1 = cr_ref.resolve_direct_fleet_ref(w0, w1, lengths)
+    l2t = jnp.stack([w0, w1], axis=2)                     # (T, C, 2, P)
+    got = resolve_batch_fleet_pallas(l2t, lengths, ids, interpret=True)
+    for name, g, full in zip(("owner", "hit", "h0", "h1"), got,
+                             (owner, hit, h0, h1)):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(jnp.take_along_axis(full, ids, axis=1)),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("formats", ["scalable", "vanilla", "mixed"])
+def test_resolve_auto_stacked_batch_path(formats):
+    """The batch path (B * 128 < P) of the stacked auto resolver against
+    the vmapped jnp auto resolver, field for field: scalable tenants carry
+    BFI_VALID words, vanilla ones an all-zero word1, and a scalable
+    tenant's unallocated active entry falls back to the walk."""
+    t, c, p, b = 4, 6, 512, 3
+    key = jax.random.fold_in(KEY, len(formats))
+    w0, w1 = _packed_fleet_words(key, t, c, p, 0.5)
+    scalable = {"scalable": [True] * t, "vanilla": [False] * t,
+                "mixed": [True, False, True, False]}[formats]
+    w1 = jnp.where(jnp.asarray(scalable)[:, None, None], w1, jnp.uint32(0))
+    lengths = jnp.asarray([c, 3, 1, c], jnp.int32)
+    ids = jax.random.randint(jax.random.fold_in(key, 1), (t, b), 0, p)
+    # the first page asked of tenant 0 has no active entry: walk it
+    w0 = w0.at[0, c - 1, ids[0, 0]].set(0)
+    w1 = w1.at[0, c - 1, ids[0, 0]].set(0)
+    l2 = jnp.stack([w0, w1], axis=-1)
+    assert resolve_lib.batch_path_ok(p, b)
+    got = resolve_lib.resolve_auto_stacked(l2, lengths, ids)
+    want = jax.vmap(resolve_lib.get_table_resolver("auto"))(l2, lengths, ids)
+    for field in got._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got, field)),
+                                      np.asarray(getattr(want, field)),
+                                      err_msg=field)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -60,13 +60,15 @@ def test_a_span_records_nothing_without_a_trace():
         ann.set_metadata(m=2)
 
 
-# a kernel method resolves each tenant's whole table, a jnp method only
-# the batch: (method, n_pages, entries resolved per tenant)
+# a whole-table kernel method resolves each tenant's whole table, a jnp
+# method or the batch kernel only the batch: (method, n_pages, entries
+# resolved per tenant)
 @pytest.mark.parametrize("method,n_pages,per_tenant", [
-    ("auto", 128, 128),          # lane-aligned: the Pallas kernel path
+    ("auto", 128, 128),          # lane-aligned: the whole-table kernels
     ("pallas_vanilla", 192, 192),
     ("vanilla", 128, 8),         # the vmapped jnp walk
     ("auto", 192, 8),            # not lane-aligned: jnp auto
+    ("auto", 2048, 8),           # 8 * 128 < 2048: the batch kernel
 ])
 def test_fleet_read_opens_one_span_per_call(tmp_path, method, n_pages,
                                             per_tenant):

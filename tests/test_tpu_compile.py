@@ -15,7 +15,9 @@ test that needs it lives in this one file.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 import types
 
 import jax
@@ -25,10 +27,14 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.serve import engine as engine_lib  # first: imports repro.core
+from repro.core import fleet as fleet_lib
+from repro.kernels.chain_resolve import ops as cr_ops
 from repro.serve.paged_decode import paged_decode_step, paged_decode_step_fused
 from repro.kernels.chain_resolve.chain_resolve import (
-    resolve_direct_fleet_pallas, resolve_direct_pallas,
-    resolve_vanilla_fleet_pallas, resolve_vanilla_pallas)
+    resolve_batch_fleet_pallas, resolve_direct_fleet_pallas,
+    resolve_direct_pallas, resolve_vanilla_fleet_pallas,
+    resolve_vanilla_pallas)
+from repro.kernels.cow_gather import ops as cg_ops
 from repro.kernels.cow_gather.cow_gather import gather_fleet_pallas, gather_pallas
 from repro.kernels.paged_attention import ops as pa_ops
 from repro.kernels.paged_attention.paged_attention import (
@@ -83,7 +89,10 @@ def test_fleet_resolve_kernels_compile(one_chip, c):
         w, ln, interpret=False), w0, ((T,), I32))
     direct = _compile(one_chip, lambda a, b, ln: resolve_direct_fleet_pallas(
         a, b, ln, interpret=False), w0, w0, ((T,), I32))
-    assert _has_kernel(van) and _has_kernel(direct)
+    batch = _compile(one_chip, lambda l2t, ln, ids: resolve_batch_fleet_pallas(
+        l2t, ln, ids, interpret=False), ((T, c, 2, P), U32), ((T,), I32),
+        ((T, 16), I32))
+    assert _has_kernel(van) and _has_kernel(direct) and _has_kernel(batch)
 
 
 def test_fleet_gather_kernel_compiles(one_chip):
@@ -131,6 +140,48 @@ def test_single_chain_kernels_compile(one_chip, kernel):
             [((30, n), U32), ((30, n), U32)]),
     }[kernel]
     assert _has_kernel(_compile(one_chip, fn, *shapes))
+
+
+@pytest.fixture
+def tpu_fleet_kernels(monkeypatch):
+    """Steer the fleet read's kernel wrappers to their compiled branch:
+    under ``JAX_PLATFORMS=cpu`` they run the kernels in interpret mode."""
+    monkeypatch.setattr(cr_ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(cg_ops, "jax",
+                        types.SimpleNamespace(default_backend=lambda: "tpu"))
+
+
+def test_deep_chain_read_resolves_the_index_in_place(one_chip,
+                                                     tpu_fleet_kernels):
+    """``fleet.read(method="auto")`` at the rocksdb-d500 shapes (two disks,
+    500 layers, 720,896 pages, 256 reads per disk) compiles with the batch
+    kernel, touches the 5.37 GiB index only as its parameter and the
+    bitcast to the kernel's (T, C, 2, P) view — no slice, copy or
+    relayout of it or of a word plane — and needs under 2 GiB of
+    temporaries (the whole-table path needed 8.1)."""
+    spec = fleet_lib.FleetSpec(
+        n_tenants=2, n_pages=720_896, page_size=1024, max_chain=500,
+        pool_capacity=2 * 180_224, lease_quantum=64, l2_per_table=8192,
+        slice_len=512)
+    fl = jax.eval_shape(lambda s: fleet_lib.create(spec, scalable=s),
+                        jax.ShapeDtypeStruct((2,), jnp.bool_))
+    fl = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                     sharding=one_chip), fl)
+    ids = jax.ShapeDtypeStruct((2, 256), I32, sharding=one_chip)
+    compiled = fleet_lib.read.lower(fl, ids, method="auto").compile()
+    hlo = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert "resolve_batch_fleet_pallas" in " ".join(
+        re.findall(r'op_name="([^"]*)"', hlo))
+    plane = spec.n_tenants * spec.max_chain * spec.n_pages
+    big = set()
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
+                         hlo, re.M):
+        if math.prod(int(d) for d in m[1].split(",") if d) >= plane:
+            big.add(m[2])
+    assert big == {"parameter", "bitcast"}, big
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * 2**30, f"{temp / 2**30:.2f} GiB of temporaries"
 
 
 @pytest.fixture
