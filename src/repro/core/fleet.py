@@ -442,12 +442,19 @@ def read(fleet: ChainFleet, page_ids: jax.Array, *, method: str = "auto"):
     function.
     """
     t, b = np.shape(page_ids)
-    whole_table = _uses_kernels(fleet.spec, method) and not (
-        method == "auto" and resolve_lib.batch_path_ok(fleet.spec.n_pages, b))
-    per_tenant = fleet.spec.n_pages if whole_table else b
-    with obs.span("fleet.read", pages=t * b, resolved=t * per_tenant,
+    with obs.span("fleet.read", pages=t * b,
+                  resolved=resolved_entries(fleet.spec, method, b),
                   method=method):
         return _read(fleet, page_ids, method=method)
+
+
+def resolved_entries(spec: FleetSpec, method: str, batch: int) -> int:
+    """Table entries one read of ``batch`` pages per tenant resolves:
+    every tenant's whole table on the whole-table kernel path, only the
+    batch on the jnp paths and where ``"auto"`` takes the batch kernel."""
+    whole_table = _uses_kernels(spec, method) and not (
+        method == "auto" and resolve_lib.batch_path_ok(spec.n_pages, batch))
+    return spec.n_tenants * (spec.n_pages if whole_table else batch)
 
 
 def _read_program(fleet: ChainFleet, page_ids: jax.Array, *, method: str):
@@ -743,6 +750,41 @@ def acquire_rows(fleet: ChainFleet, t: int, n: int):
         alloc_count=fleet.alloc_count + jnp.asarray(need),
     )
     return out, rows_t
+
+
+def grow(fleet: ChainFleet, *, tenants: int = 0,
+         quanta: int = 0) -> ChainFleet:
+    """The same fleet with ``tenants`` more tenant slots and ``quanta``
+    more lease quanta of pool. New slots are empty length-1 chains
+    (vanilla, no leases); new quanta are free; every existing tenant,
+    lease and pool row keeps its place."""
+    if tenants == 0 and quanta == 0:
+        return fleet
+    spec = dataclasses.replace(
+        fleet.spec, n_tenants=fleet.spec.n_tenants + tenants,
+        pool_capacity=fleet.spec.pool_capacity
+        + quanta * fleet.spec.lease_quantum)
+
+    def pad(a, n, value):
+        return jnp.concatenate([a, jnp.full((n, *a.shape[1:]), value,
+                                            a.dtype)])
+
+    return ChainFleet(
+        spec=spec,
+        l1=pad(fleet.l1, tenants, 0),
+        l2=pad(fleet.l2, tenants, 0),
+        pool=pad(fleet.pool, quanta * spec.lease_quantum, 0),
+        lease_owner=pad(fleet.lease_owner, quanta, -1),
+        lease_index=jnp.pad(fleet.lease_index, ((0, tenants), (0, quanta)),
+                            constant_values=-1),
+        lease_count=pad(fleet.lease_count, tenants, 0),
+        alloc_count=pad(fleet.alloc_count, tenants, 0),
+        length=pad(fleet.length, tenants, 1),
+        scalable=pad(fleet.scalable, tenants, False),
+        overflow=pad(fleet.overflow, tenants, False),
+        snap_dropped=pad(fleet.snap_dropped, tenants, False),
+        cold_count=pad(fleet.cold_count, tenants, 0),
+    )
 
 
 def install_tenant(fleet: ChainFleet, t: int, *, l1, l2, length: int,

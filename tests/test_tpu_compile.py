@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.serve import engine as engine_lib  # first: imports repro.core
+from repro.core import depth_classes
 from repro.core import fleet as fleet_lib
 from repro.kernels.chain_resolve import ops as cr_ops
 from repro.serve.paged_decode import paged_decode_step, paged_decode_step_fused
@@ -182,6 +183,56 @@ def test_deep_chain_read_resolves_the_index_in_place(one_chip,
     assert big == {"parameter", "bitcast"}, big
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * 2**30, f"{temp / 2**30:.2f} GiB of temporaries"
+
+
+def test_batch_resolve_kernel_compiles_at_depth_1000(one_chip):
+    """The batch kernel stages a (1000, 2, 128) tile column per page for
+    the deepest class of ``chains-1to1000`` (two disks of 262,144 pages):
+    1 MB a block in the index's (2, 128) tiling, 2 MB double buffered,
+    inside the default scoped VMEM."""
+    batch = _compile(one_chip, lambda l2t, ln, ids: resolve_batch_fleet_pallas(
+        l2t, ln, ids, interpret=False), ((2, 1000, 2, 262_144), U32),
+        ((2,), I32), ((2, 256), I32))
+    assert _has_kernel(batch)
+
+
+def test_depth_sweep_read_fits_one_v5e(one_chip, tpu_fleet_kernels):
+    """``depth_classes.read`` at the ``chains-1to1000`` shapes (ten disks
+    at depths 1, 50, 100, 500 and 1,000, 262,144 pages, 65,536 live
+    clusters of 4 KiB each, 256 reads per disk) compiles as one program
+    with the batch kernel in every class, holds 3,302 layers of index
+    (a dense stack would hold 10,000), reads each class's index in place,
+    and fits the chip with room to spare."""
+    classes = []
+    for depth in (1, 50, 100, 500, 1000):
+        spec = fleet_lib.FleetSpec(
+            n_tenants=2, n_pages=262_144, page_size=1024, max_chain=depth,
+            pool_capacity=2 * 65_536, lease_quantum=64, l2_per_table=8192,
+            slice_len=512)
+        fl = jax.eval_shape(lambda s: fleet_lib.create(spec, scalable=s),
+                            jax.ShapeDtypeStruct((2,), jnp.bool_))
+        classes.append(jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), fl))
+    classed = depth_classes.assemble(classes)
+    assert classed.index_bytes() == 3302 * 262_144 * 8
+    ids = jax.ShapeDtypeStruct((10, 256), I32, sharding=one_chip)
+    compiled = depth_classes.read.lower(classed, ids, method="auto").compile()
+    hlo = compiled.as_text()
+    names = " ".join(re.findall(r'op_name="([^"]*)"', hlo))
+    for depth in (1, 50, 100, 500, 1000):
+        assert f"fleet.class{depth}/fleet.resolve/" in names
+    assert names.count("resolve_batch_fleet_pallas") >= 5
+    plane = 2 * 1000 * 262_144
+    big = set()
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
+                         hlo, re.M):
+        if math.prod(int(d) for d in m[1].split(",") if d) >= plane:
+            big.add(m[2])
+    assert big == {"parameter", "bitcast"}, big
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used <= V5E_HBM_BYTES - 2**30, f"{used / 2**30:.2f} GiB"
 
 
 @pytest.fixture
