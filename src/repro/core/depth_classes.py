@@ -127,14 +127,17 @@ def read(classed: ClassedFleet, page_ids: jax.Array, *,
     the disk's own chain and ``ptr`` a row of its class's pool.
 
     The call opens one ``snapstore.fleet.read`` span with ``fleet.read``'s
-    arguments (``resolved`` summed over the classes) and ``classes`` (how
+    arguments (``resolved`` summed over the classes; ``gather`` the forms
+    the classes ran, comma-joined where they differ) and ``classes`` (how
     many) and ``index_bytes`` (L2 bytes held on the device).
     """
     n, b = np.shape(page_ids)
     resolved = sum(fleet_lib.resolved_entries(f.spec, method, b)
                    for f in classed.classes)
+    gather = ",".join(sorted({fleet_lib.gather_form(f, method)
+                              for f in classed.classes}))
     with obs.span("fleet.read", pages=n * b, resolved=resolved,
-                  method=method, classes=len(classed.classes),
+                  method=method, gather=gather, classes=len(classed.classes),
                   index_bytes=classed.index_bytes()):
         return _read(classed, page_ids, method=method)
 

@@ -53,6 +53,7 @@ from repro.core import chain as chain_lib
 from repro.core import format as fmt
 from repro.core import resolve as resolve_lib
 from repro.core.chain import Chain, ChainSpec
+from repro.kernels.cow_gather import cow_gather
 from repro.kernels.cow_gather import ops as cow_ops
 
 
@@ -437,15 +438,24 @@ def read(fleet: ChainFleet, page_ids: jax.Array, *, method: str = "auto"):
     enqueue of the compiled program (module ``jit_read``), with the pages
     asked for (T×B), the table entries the resolver resolves (T×n_pages on
     the whole-table kernel path, T×B on the jnp paths and where ``"auto"``
-    takes the batch kernel, ``resolve.batch_path_ok``) and the method.
-    ``read.lower`` lowers that program ahead of time, as on any jitted
-    function.
+    takes the batch kernel, ``resolve.batch_path_ok``), the method and
+    the gather that ran (``gather_form``). ``read.lower`` lowers that
+    program ahead of time, as on any jitted function.
     """
     t, b = np.shape(page_ids)
     with obs.span("fleet.read", pages=t * b,
                   resolved=resolved_entries(fleet.spec, method, b),
-                  method=method):
+                  method=method, gather=gather_form(fleet, method)):
         return _read(fleet, page_ids, method=method)
+
+
+def gather_form(fleet: ChainFleet, method: str) -> str:
+    """The gather ``read`` runs: the kernel staging the pool's 8-row
+    sublane groups (``"group"``) or single rows (``"row"``,
+    ``cow_gather.gather_group``), or the jnp gather (``"jnp"``)."""
+    if not _uses_kernels(fleet.spec, method):
+        return "jnp"
+    return cow_gather.gather_form(fleet.pool.shape[0], fleet.pool.dtype)
 
 
 def resolved_entries(spec: FleetSpec, method: str, batch: int) -> int:

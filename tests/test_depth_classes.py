@@ -281,7 +281,7 @@ def test_one_read_span_per_class_read(tmp_path, grown):
     rows = sum(f.spec.n_tenants for f in classed.classes)   # free ones too
     for *_, args in spans:
         assert args == dict(pages=len(DEPTHS) * 8, resolved=rows * 8,
-                            method="auto", classes=4,
+                            method="auto", gather="jnp", classes=4,
                             index_bytes=classed.index_bytes())
 
 

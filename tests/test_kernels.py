@@ -13,7 +13,8 @@ from repro.kernels.chain_resolve.chain_resolve import (
     resolve_direct_pallas, resolve_vanilla_fleet_pallas,
     resolve_vanilla_pallas)
 from repro.kernels.cow_gather import ref as cg_ref
-from repro.kernels.cow_gather.cow_gather import gather_fleet_pallas, gather_pallas
+from repro.kernels.cow_gather.cow_gather import (
+    gather_fleet_pallas, gather_form, gather_pallas)
 from repro.kernels.paged_attention import ops as pa_ops
 from repro.kernels.paged_attention import ref as pa_ref
 from repro.kernels.paged_attention.paged_attention import (
@@ -173,6 +174,65 @@ def test_cow_gather_sweep(dtype, rows, page):
     o1 = cg_ref.gather_ref(pool, idx, found)
     o2 = gather_pallas(pool, idx, found, interpret=True)
     np.testing.assert_array_equal(np.asarray(o1), np.asarray(o2))
+
+
+def _bit_pool(rows, page, dtype):
+    """A pool of arbitrary bit patterns (NaNs and -0.0 among them), so a
+    gather that rounds or canonicalises a payload reads wrong."""
+    bits = jnp.dtype(dtype).itemsize * 8
+    raw = jax.random.bits(KEY, (rows, page), getattr(jnp, f"uint{bits}"))
+    return jax.lax.bitcast_convert_type(raw, dtype)
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
+
+
+# (pool rows, requested rows, found; None = all found, or the pool rows
+# that read as unfound). A 32-bit pool of 64 rows is 8 sublane groups.
+_GATHER_CASES = {
+    # every offset 0-7 of a middle group, then the pool's last group
+    "offsets": (64, [24, 25, 26, 27, 28, 29, 30, 31,
+                     56, 57, 58, 59, 60, 61, 62, 63], None),
+    # repeated rows; unfound requests, one of them past the pool's end
+    "repeats": (64, [5, 5, 13, 5, 63, 63, 0, 0,
+                     13, 9999, 7, 7, 40, 41, 40, 5],
+                [0, 1, 3, 9]),
+    # 3 x 13 requests: a batch that is no multiple of 8
+    "odd_batch": (64, list(range(63, 24, -1)), [2]),
+    # 37 rows: no whole sublane groups, so the (R, 1, P) row view
+    "ragged_pool": (37, [36, 0, 35, 8, 7, 36, 1, 16,
+                         31, 32, 33, 34, 2, 3, 4, 5], [5]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(_GATHER_CASES))
+@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "chain"])
+def test_cow_gather_reads_every_row_bit_for_bit(fleet, case, dtype):
+    """Both gathers against their oracles, bit for bit, whichever view of
+    the pool they stage: a 32-bit pool of whole 8-row groups is read
+    through the group view, any other through the row view."""
+    n_rows, wanted, unfound = _GATHER_CASES[case]
+    pool = _bit_pool(n_rows, 256, dtype)
+    rows = jnp.asarray(wanted, jnp.int32)
+    found = jnp.ones(rows.shape, bool)
+    if unfound is not None:
+        found = found.at[jnp.asarray(unfound)].set(False)
+    wide = jnp.dtype(dtype).itemsize == 4
+    want_form = "group" if wide and n_rows % 8 == 0 else "row"
+    assert gather_form(n_rows, dtype) == want_form
+    if fleet:
+        t = 3 if case == "odd_batch" else 2
+        rows, found = rows.reshape(t, -1), found.reshape(t, -1)
+        want = cg_ref.gather_fleet_ref(pool, rows, found)
+        got = gather_fleet_pallas(pool, rows, found, interpret=True)
+    else:
+        want = cg_ref.gather_ref(pool, rows, found)
+        got = gather_pallas(pool, rows, found, interpret=True)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    assert not _bits(got)[~np.asarray(found)].any()
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])

@@ -4,6 +4,7 @@ program still compiled as ``jit_read`` with its ``fleet.resolve`` and
 ``fleet.gather`` scopes in the op metadata, and the serving and
 maintenance spans, each checked on a short CPU trace."""
 
+import math
 import re
 
 import jax
@@ -43,11 +44,11 @@ def record(tmp_path, fn):
     return sorted(out, key=lambda s: s[1])
 
 
-def small_fleet(n_pages):
+def small_fleet(n_pages, pool_capacity=256):
     spec = fleet_lib.FleetSpec(n_tenants=2, n_pages=n_pages, page_size=128,
-                               max_chain=4, pool_capacity=256,
-                               lease_quantum=16, l2_per_table=64,
-                               slice_len=16)
+                               max_chain=4, pool_capacity=pool_capacity,
+                               lease_quantum=math.gcd(pool_capacity, 16),
+                               l2_per_table=64, slice_len=16)
     fl = fleet_lib.create(spec, scalable=jnp.asarray([True, False]))
     ids = jnp.tile(jnp.arange(8, dtype=jnp.int32)[None], (2, 1))
     fl = fleet_lib.write(fl, ids, jnp.ones((2, 8, 128)))
@@ -61,18 +62,20 @@ def test_a_span_records_nothing_without_a_trace():
 
 
 # a whole-table kernel method resolves each tenant's whole table, a jnp
-# method or the batch kernel only the batch: (method, n_pages, entries
-# resolved per tenant)
-@pytest.mark.parametrize("method,n_pages,per_tenant", [
-    ("auto", 128, 128),          # lane-aligned: the whole-table kernels
-    ("pallas_vanilla", 192, 192),
-    ("vanilla", 128, 8),         # the vmapped jnp walk
-    ("auto", 192, 8),            # not lane-aligned: jnp auto
-    ("auto", 2048, 8),           # 8 * 128 < 2048: the batch kernel
+# method or the batch kernel only the batch; the gather kernel stages the
+# pool's 8-row groups where its rows come in whole groups: (method,
+# n_pages, pool rows, entries resolved per tenant, gather form)
+@pytest.mark.parametrize("method,n_pages,pool,per_tenant,gather", [
+    ("auto", 128, 256, 128, "group"),   # lane-aligned: whole-table kernels
+    ("pallas_vanilla", 192, 256, 192, "group"),
+    ("vanilla", 128, 256, 8, "jnp"),    # the vmapped jnp walk
+    ("auto", 192, 256, 8, "jnp"),       # not lane-aligned: jnp auto
+    ("auto", 2048, 256, 8, "group"),    # 8 * 128 < 2048: the batch kernel
+    ("auto", 2048, 260, 8, "row"),      # 260 rows: no whole 8-row groups
 ])
-def test_fleet_read_opens_one_span_per_call(tmp_path, method, n_pages,
-                                            per_tenant):
-    fl, ids = small_fleet(n_pages)
+def test_fleet_read_opens_one_span_per_call(tmp_path, method, n_pages, pool,
+                                            per_tenant, gather):
+    fl, ids = small_fleet(n_pages, pool)
     jax.block_until_ready(fleet_lib.read(fl, ids, method=method))
 
     def reads():
@@ -82,7 +85,8 @@ def test_fleet_read_opens_one_span_per_call(tmp_path, method, n_pages,
     spans = record(tmp_path, reads)
     assert [s[0] for s in spans] == ["fleet.read"] * 3
     for _, _, _, args in spans:
-        assert args == dict(pages=16, resolved=2 * per_tenant, method=method)
+        assert args == dict(pages=16, resolved=2 * per_tenant, method=method,
+                            gather=gather)
 
 
 def test_read_lowers_as_jit_read_with_its_scopes():

@@ -83,6 +83,17 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _ops_at_least(hlo: str, n: int) -> set:
+    """The kinds of the compiled program's ops whose result holds at
+    least ``n`` elements."""
+    big = set()
+    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
+                         hlo, re.M):
+        if math.prod(int(d) for d in m[1].split(",") if d) >= n:
+            big.add(m[2])
+    return big
+
+
 @pytest.mark.parametrize("c", [1, 500])
 def test_fleet_resolve_kernels_compile(one_chip, c):
     w0 = ((T, c, P), U32)
@@ -97,11 +108,18 @@ def test_fleet_resolve_kernels_compile(one_chip, c):
 
 
 def test_fleet_gather_kernel_compiles(one_chip):
+    """The gather stages 8-row groups of the pool through its bitcast
+    (R // 8, 8, P) view: no op but the parameter and that bitcast holds
+    the pool, and nothing is copied to feed the kernel."""
+    rows = T * 4096
     compiled = _compile(
         one_chip, lambda pool, r, f: gather_fleet_pallas(
             pool, r, f, interpret=False),
-        ((T * 4096, 128), jnp.float32), ((T, 512), I32), ((T, 512), jnp.bool_))
+        ((rows, 128), jnp.float32), ((T, 512), I32), ((T, 512), jnp.bool_))
     assert _has_kernel(compiled)
+    assert _ops_at_least(compiled.as_text(), rows * 128) == {"parameter",
+                                                             "bitcast"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.parametrize("c", [1, 500])
@@ -156,10 +174,11 @@ def test_deep_chain_read_resolves_the_index_in_place(one_chip,
                                                      tpu_fleet_kernels):
     """``fleet.read(method="auto")`` at the rocksdb-d500 shapes (two disks,
     500 layers, 720,896 pages, 256 reads per disk) compiles with the batch
-    kernel, touches the 5.37 GiB index only as its parameter and the
-    bitcast to the kernel's (T, C, 2, P) view — no slice, copy or
-    relayout of it or of a word plane — and needs under 2 GiB of
-    temporaries (the whole-table path needed 8.1)."""
+    kernel, touches the 5.37 GiB index and the 1.375 GiB pool only as
+    parameters and bitcasts to the kernels' (T, C, 2, P) and
+    (R // 8, 8, P) views — no slice, copy or relayout of either or of a
+    word plane — and needs under 64 MiB of temporaries (the whole-table
+    path needed 8.1 GiB, the pool's row view 1.375 GiB)."""
     spec = fleet_lib.FleetSpec(
         n_tenants=2, n_pages=720_896, page_size=1024, max_chain=500,
         pool_capacity=2 * 180_224, lease_quantum=64, l2_per_table=8192,
@@ -174,15 +193,11 @@ def test_deep_chain_read_resolves_the_index_in_place(one_chip,
     assert _has_kernel(compiled)
     assert "resolve_batch_fleet_pallas" in " ".join(
         re.findall(r'op_name="([^"]*)"', hlo))
-    plane = spec.n_tenants * spec.max_chain * spec.n_pages
-    big = set()
-    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
-                         hlo, re.M):
-        if math.prod(int(d) for d in m[1].split(",") if d) >= plane:
-            big.add(m[2])
+    pool = spec.pool_capacity * spec.page_size     # less than the index
+    big = _ops_at_least(hlo, pool)
     assert big == {"parameter", "bitcast"}, big
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 2 * 2**30, f"{temp / 2**30:.2f} GiB of temporaries"
+    assert temp < 64 * 2**20, f"{temp / 2**20:.1f} MiB of temporaries"
 
 
 def test_batch_resolve_kernel_compiles_at_depth_1000(one_chip):
@@ -201,8 +216,8 @@ def test_depth_sweep_read_fits_one_v5e(one_chip, tpu_fleet_kernels):
     at depths 1, 50, 100, 500 and 1,000, 262,144 pages, 65,536 live
     clusters of 4 KiB each, 256 reads per disk) compiles as one program
     with the batch kernel in every class, holds 3,302 layers of index
-    (a dense stack would hold 10,000), reads each class's index in place,
-    and fits the chip with room to spare."""
+    (a dense stack would hold 10,000), reads each class's index and pool
+    in place, and fits the chip with room to spare."""
     classes = []
     for depth in (1, 50, 100, 500, 1000):
         spec = fleet_lib.FleetSpec(
@@ -222,14 +237,12 @@ def test_depth_sweep_read_fits_one_v5e(one_chip, tpu_fleet_kernels):
     for depth in (1, 50, 100, 500, 1000):
         assert f"fleet.class{depth}/fleet.resolve/" in names
     assert names.count("resolve_batch_fleet_pallas") >= 5
-    plane = 2 * 1000 * 262_144
-    big = set()
-    for m in re.finditer(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(",
-                         hlo, re.M):
-        if math.prod(int(d) for d in m[1].split(",") if d) >= plane:
-            big.add(m[2])
+    pool = 2 * 65_536 * 1024       # each class's; less than any index
+    big = _ops_at_least(hlo, pool)
     assert big == {"parameter", "bitcast"}, big
     mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 * 2**20, (
+        f"{mem.temp_size_in_bytes / 2**20:.1f} MiB of temporaries")
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used <= V5E_HBM_BYTES - 2**30, f"{used / 2**30:.2f} GiB"
